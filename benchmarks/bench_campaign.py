@@ -30,6 +30,7 @@ from repro.core.campaign import (DEFAULT_POLICIES, LAST_PHASES,
                                  SUMMARY_STATS, campaign_table,
                                  run_campaign, run_campaign_serial)
 from repro.core.scenarios import scenario_names
+from repro.launch.compile_cache import enable_compile_cache
 
 PARITY_TOL = 1e-5
 #: compiled backends only: the scan kernel's in-kernel ridge retrain
@@ -169,6 +170,7 @@ def main():
                          "core)")
     ap.add_argument("--no-artifact", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         scenarios = ("baseline", "flash-crowd", "stale-predictions")

@@ -44,6 +44,7 @@ from repro.core.campaign import stack_clusters
 from repro.core.rng import rng_seed
 from repro.core.scenarios import get_scenario
 from repro.core.simulator import SimStepper, _build_cluster
+from repro.launch.compile_cache import enable_compile_cache
 
 VARIANTS = ("no-retry", "naive-retries", "breaker-admission")
 #: variant -> (scenario, resilience override applied to the spec)
@@ -179,6 +180,7 @@ def main():
                     help="reduced grid + hard collapse gate (CI)")
     ap.add_argument("--no-artifact", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         seeds, overrides = tuple(range(4)), dict(n_trials=4)

@@ -47,6 +47,7 @@ from repro.core.campaign import (SUMMARY_STATS, compiled_coverage,
                                  stack_clusters)
 from repro.core.scenarios import get_scenario
 from repro.core.simulator import SimStepper, _build_cluster
+from repro.launch.compile_cache import enable_compile_cache
 
 PARITY_TOL = 1e-5
 SPEEDUP_GATE = 20.0      # large-config reactive row (full mode)
@@ -214,6 +215,7 @@ def main():
     ap.add_argument("--no-fleet", action="store_true",
                     help="skip the million-request fleet demo")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         # coverage gate first: backend="auto" must never silently fall

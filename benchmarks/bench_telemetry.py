@@ -46,6 +46,7 @@ from repro.core.scenarios import get_scenario, scenario_names
 from repro.core.simulator import _build_cluster, run_sim
 from repro.core.telemetry import (COMPONENTS, TRACE_IDX, TraceConfig,
                                   tail_attribution)
+from repro.launch.compile_cache import enable_compile_cache
 
 PARITY_TOL = 1e-5            # per-field serial-vs-compiled trace drift
 SUM_TOL = 1e-6               # decomposition sum rule on served rows
@@ -216,6 +217,7 @@ def main():
                     help="shrunken cell + parity/overhead gate (CI)")
     ap.add_argument("--no-artifact", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         drift, sum_err = trace_parity(PARITY_SMOKE)

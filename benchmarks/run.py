@@ -44,6 +44,8 @@ def manifest() -> dict:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_adaptation, bench_binning, bench_breakdown,
                             bench_campaign, bench_capacity,
                             bench_correlations, bench_covariability,

@@ -130,7 +130,6 @@ def kernel_specs(scenarios: Optional[Sequence[str]] = None,
 def trace_kernel(cfg, policy: str):
     """make_jaxpr the kernel closure for (cfg, policy) — trace only."""
     import jax
-    from jax.experimental import enable_x64
 
     from repro.core.simcore import _build_kernel, _lower
     from repro.core.simulator import _build_cluster
@@ -138,18 +137,18 @@ def trace_kernel(cfg, policy: str):
     cluster = _build_cluster(cfg)
     st, consts, xs, carry0, _aux = _lower(cluster, policy, None)
     run = _build_kernel(st)
-    with enable_x64():
+    with jax.enable_x64():
         return jax.make_jaxpr(run)(consts, xs, carry0)
 
 
 def _subjaxprs(eqn) -> Iterator:
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     for v in eqn.params.values():
         vals = v if isinstance(v, (tuple, list)) else (v,)
         for item in vals:
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, ClosedJaxpr):
                 yield item.jaxpr
-            elif isinstance(item, jax.core.Jaxpr):
+            elif isinstance(item, Jaxpr):
                 yield item
 
 

@@ -26,14 +26,14 @@ held as dense arrays carried through the scan:
   break, ``mask_inactive``) — in-kernel, per step;
 * the capacity plane (decide / wake / preempt / admission / ledger) and
   the closed-loop :class:`~repro.core.online.OnlineFleet` (ridge
-  retrains via ``jnp.linalg.solve``, rolling-accuracy fallback) are
+  retrains via ``_ridge_solve``, rolling-accuracy fallback) are
   carried as dense per-trial state with the serial update order
   preserved step for step.
 
 **Serial-reference contract**: the serial stepper is the semantics; the
 kernel must agree with it to <= 1e-5 relative on every summary stat for
 every supported config (``tests/test_simcore.py`` gates all registered
-scenarios).  All float state runs under ``jax.experimental.enable_x64``
+scenarios).  All float state runs under ``jax.enable_x64``
 so the only divergence from the numpy path is libm/XLA ulp noise.
 Pre-drawn noise (``_Cluster.z_rtt`` / ``z_pred`` / the RandomChoice
 stream) is fed in as scan inputs, so compiled and serial runs consume
@@ -69,13 +69,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
+from jax.scipy.linalg import lu_solve
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:                            # moved in newer jax; 0.4.x location first
-    from jax.experimental.shard_map import shard_map
-except ImportError:             # pragma: no cover - newer jax
-    from jax.sharding import shard_map
 
 from repro.core.balancer import BUSY_PENALTY, POLICIES
 from repro.core.capacity import CapacityConfig, membership_timeline
@@ -92,17 +87,18 @@ __all__ = ["supports", "run_compiled", "run_sim_compiled",
 _EV_KIND = {"scale": 0, "preempt_down": 1, "preempt_up": 2, "churn": 3,
             "group_down": 4}
 
-#: segment-sum backend for the from-scratch bucket reductions (count
-#: resyncs at churn, snapshot refreshes): None auto-selects the Pallas
-#: kernel on TPU and the XLA sort-plan elsewhere; tests pin "pallas"
-#: (interpret mode on CPU) or "xla" explicitly.
+#: segment-sum path for the from-scratch bucket reductions (count
+#: resyncs at churn, snapshot refreshes): None picks the Pallas kernel
+#: on TPU and the XLA sort plan elsewhere; tests pin "xla", "pallas"
+#: (compiled for the TPU) or "interpret" (the kernel in Pallas
+#: interpret mode, on any backend).
 _SEGSUM_BACKEND: Optional[str] = None
 
 
-def _pallas_segsum() -> bool:
+def _segsum_backend() -> str:
     if _SEGSUM_BACKEND is not None:
-        return _SEGSUM_BACKEND == "pallas"
-    return jax.default_backend() == "tpu"
+        return _SEGSUM_BACKEND
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 # ----------------------------------------------------------------------
@@ -402,7 +398,7 @@ def _lower(cluster: _Cluster, policy: str, seed_blocks=None):
         # Pallas segment-sum kernel on TPU, else the XLA sort plan
         na_key = np.asarray(cluster.node_of) * A \
             + cluster.app_of[None, :]
-        if _pallas_segsum():
+        if _segsum_backend() != "xla":
             consts["na_key"] = na_key.astype(np.int32)
         else:
             perm, bstart, bend = _bucket_plan(na_key, N * A)
@@ -589,6 +585,25 @@ def _take_hi(elig, k):
     return elig & (cs <= k[:, None])
 
 
+def _ridge_solve(G, b):
+    """Batched float64 solve of ``G x = b`` for the closed-loop ridge
+    retrain: a float32 LU factorisation plus two float64
+    iterative-refinement steps.  XLA:TPU has no float64 LU, so this one
+    formulation serves every backend.  On the ridge systems of the
+    large drift-fallback cell (condition number up to ~2e4) two steps
+    bring the error against the float64 serial solve to ~1e-13."""
+    lu, piv, _ = lax.linalg.lu(G.astype(jnp.float32))
+
+    def solve32(r):
+        x = lu_solve((lu, piv), r.astype(jnp.float32)[..., None])
+        return x[..., 0].astype(G.dtype)
+
+    x = solve32(b)
+    for _ in range(2):
+        x = x + solve32(b - jnp.einsum("...de,...e->...d", G, x))
+    return x
+
+
 # ----------------------------------------------------------------------
 # kernel builder
 def _build_kernel(st: _Static):
@@ -601,7 +616,7 @@ def _build_kernel(st: _Static):
     D = N + A
     Wn, Wa = st.obs_window, st.acc_window
     full_actual, need_live, need_snap = _count_flags(st)
-    seg_pallas = _pallas_segsum()
+    seg_backend = _segsum_backend()
 
     def run(c, xs, carry0):
         T = c["node_of"].shape[0]
@@ -644,10 +659,13 @@ def _build_kernel(st: _Static):
                 resyncs (churn) and snapshot refreshes.  Pallas
                 segment-sum on TPU, XLA sort plan elsewhere."""
                 busyb = busy_src > now
-                if seg_pallas:
+                if seg_backend != "xla":
+                    # 0/1 masks summing to <= R: exact in float32, the
+                    # kernel's dtype on the TPU
                     from repro.kernels.segment_sum import segment_sum
-                    flat = segment_sum(busyb.astype(jnp.float64),
-                                       c["na_key"], N * A)
+                    flat = segment_sum(busyb.astype(jnp.float32),
+                                       c["na_key"], N * A,
+                                       interpret=seg_backend == "interpret")
                 else:
                     flat = bucket_sum(busyb.astype(jnp.float64),
                                       c["perm"], c["bstart"], c["bend"])
@@ -1251,7 +1269,7 @@ def _build_kernel(st: _Static):
                                            cr["obs_X"]) \
                                 + st.lam * jnp.eye(D, dtype=jnp.float64)
                             b = jnp.einsum("wtd,wt->td", Xw, cr["obs_y"])
-                            Wa_ = jnp.linalg.solve(G, b[..., None])[..., 0]
+                            Wa_ = _ridge_solve(G, b)
                             okm = n_eff >= st.min_obs
                             W_ = W_.at[:, a_].set(
                                 jnp.where(okm[:, None], Wa_, W_[:, a_]))
@@ -1789,10 +1807,10 @@ def cache_stats() -> Dict[str, int]:
 
 
 def _get_fn(st: _Static, mode: str, ndev: int, trees=None):
-    # the segment-sum backend is trace-time state (_pallas_segsum() is
+    # the segment-sum backend is trace-time state (_segsum_backend() is
     # read inside _build_kernel), so it must be part of the cache key
     # or a test flipping _SEGSUM_BACKEND would get a stale kernel
-    key = (st, mode, ndev, _pallas_segsum())
+    key = (st, mode, ndev, _segsum_backend())
     fn = _FN_CACHE.get(key)
     if fn is not None:
         _FN_STATS["hits"] += 1
@@ -1804,11 +1822,11 @@ def _get_fn(st: _Static, mode: str, ndev: int, trees=None):
         consts, xs, carry0, ys_keys = trees
         mesh = Mesh(np.array(jax.devices()), axis_names=("trials",))
         cr_spec = _spec_tree(carry0)
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             run, mesh=mesh,
             in_specs=(_spec_tree(consts), _spec_tree(xs), cr_spec),
             out_specs=(cr_spec, _spec_tree(ys_keys)),
-            check_rep=False))
+            check_vma=False))
     else:
         fn = jax.jit(run)
     _FN_CACHE[key] = fn
@@ -1852,7 +1870,7 @@ def _execute(st, consts, xs, carry0, force_single=False):
     T = carry0["busy"].shape[0]
     use_shard = not force_single and ndev > 1 and _shardable(st)
     Tp = -(-T // ndev) * ndev if use_shard else T
-    with enable_x64():
+    with jax.enable_x64():
         if Tp != T:
             consts = _pad_trials(consts, T, Tp)
             xs = _pad_trials(xs, T, Tp)
@@ -2055,14 +2073,14 @@ def prepare_compiled(cluster: _Cluster, policy: str, *,
     if reason is not None:
         raise ValueError(f"simcore cannot run this config: {reason}")
     st, consts, xs, carry0, aux = _lower(cluster, policy, seed_blocks)
-    with enable_x64():
+    with jax.enable_x64():
         cj = {k: jnp.asarray(v) for k, v in consts.items()}
         xj = {k: jnp.asarray(v) for k, v in xs.items()}
         crj = {k: jnp.asarray(v) for k, v in carry0.items()}
         fn = _get_fn(st, "jit", 1)
 
     def run() -> Dict[str, np.ndarray]:
-        with enable_x64():
+        with jax.enable_x64():
             final, ys = fn(cj, xj, crj)
             final_np = {k: np.asarray(v) for k, v in final.items()}
             ys_np = {k: np.asarray(v) for k, v in ys.items()}

@@ -12,20 +12,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-
-
-def _vmem(shape, dtype):
-    if _HAS_PLTPU:
-        return pltpu.VMEM(shape, dtype)
-    return pl.MemorySpace.ANY(shape, dtype)  # pragma: no cover
 
 
 def _kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -79,10 +68,8 @@ def decode_attention(q, k, v, kv_len, *, block: int = 512,
 
     kernel = functools.partial(_kernel, scale=D ** -0.5, block=bs,
                                n_blocks=nb)
-    kwargs = {}
-    if _HAS_PLTPU and not interpret:  # pragma: no cover (TPU only)
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"))
 
     out = pl.pallas_call(
         kernel,
@@ -96,11 +83,11 @@ def decode_attention(q, k, v, kv_len, *, block: int = 512,
         out_specs=pl.BlockSpec((1, G, Dv), lambda b, j: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B * KV, G, Dv), q.dtype),
         scratch_shapes=[
-            _vmem((G,), jnp.float32),
-            _vmem((G,), jnp.float32),
-            _vmem((G, Dv), jnp.float32),
+            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((G, Dv), jnp.float32),
         ],
         interpret=interpret,
-        **kwargs,
+        compiler_params=params,
     )(q2, k2, v2, lens)
     return out.reshape(B, 1, H, Dv)

@@ -16,12 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU compiler params are optional on the CPU/interpret path
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -99,10 +94,8 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
         _kernel, scale=D ** -0.5, causal=causal, block_q=bq, block_k=bk,
         n_kv_blocks=nk, seq_q=Sq, seq_k=Skv)
 
-    kwargs = {}
-    if _HAS_PLTPU and not interpret:  # pragma: no cover (TPU only)
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     out = pl.pallas_call(
         kernel,
@@ -115,18 +108,12 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
         out_specs=pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * KV * G, Sq, Dv), q.dtype),
         scratch_shapes=[
-            _vmem((bq,), jnp.float32),
-            _vmem((bq,), jnp.float32),
-            _vmem((bq, Dv), jnp.float32),
+            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         interpret=interpret,
-        **kwargs,
+        compiler_params=params,
     )(q2, k2, v2)
     return (out.reshape(B, KV, G, Sq, Dv).transpose(0, 3, 1, 2, 4)
             .reshape(B, Sq, H, Dv))
-
-
-def _vmem(shape, dtype):
-    if _HAS_PLTPU:
-        return pltpu.VMEM(shape, dtype)
-    return pl.MemorySpace.ANY(shape, dtype)  # pragma: no cover

@@ -13,18 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAS_PLTPU = False
-
-
-def _vmem(shape, dtype):
-    if _HAS_PLTPU:
-        return pltpu.VMEM(shape, dtype)
-    return pl.MemorySpace.ANY(shape, dtype)  # pragma: no cover
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, w_ref, o_ref, acc_scr, *, n_d_blocks: int):
@@ -56,11 +45,9 @@ def gmm(x, w, *, block_c: int = 128, block_f: int = 128, block_d: int = 128,
     nc, nf, nd = C // bc, F // bf, D // bd
 
     kernel = functools.partial(_kernel, n_d_blocks=nd)
-    kwargs = {}
-    if _HAS_PLTPU and not interpret:  # pragma: no cover (TPU only)
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"))
 
     return pl.pallas_call(
         kernel,
@@ -71,7 +58,7 @@ def gmm(x, w, *, block_c: int = 128, block_f: int = 128, block_d: int = 128,
         ],
         out_specs=pl.BlockSpec((1, bc, bf), lambda e, i, j, d: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, C, F), x.dtype),
-        scratch_shapes=[_vmem((bc, bf), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
         interpret=interpret,
-        **kwargs,
+        compiler_params=params,
     )(x, w)

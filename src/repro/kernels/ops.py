@@ -1,12 +1,10 @@
 """jit'd wrappers dispatching Pallas kernels vs XLA reference paths.
 
-On the CPU container, Pallas runs in interpret mode (correctness only);
-the model's default compute path is the blockwise-XLA implementation.
-``use_pallas`` selects the kernel path on real TPUs.
+``use_pallas`` selects the kernel path; the default is the XLA reference.
+A kernel runs in Pallas interpret mode (correctness only, any backend)
+only when the caller passes ``interpret=True``.
 """
 from __future__ import annotations
-
-import jax
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention as _decode_pallas
@@ -16,42 +14,33 @@ from repro.kernels.segment_sum import segment_sum as _segsum_pallas
 from repro.kernels.ssd import ssd as _ssd_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def flash_attention(q, k, v, *, causal=True, use_pallas=False,
-                    interpret=None):
+                    interpret=False):
     if use_pallas:
-        interp = (not _on_tpu()) if interpret is None else interpret
-        return _flash_pallas(q, k, v, causal=causal, interpret=interp)
+        return _flash_pallas(q, k, v, causal=causal, interpret=interpret)
     return ref.attention_ref(q, k, v, causal=causal)
 
 
-def decode_attention(q, k, v, kv_len, *, use_pallas=False, interpret=None):
+def decode_attention(q, k, v, kv_len, *, use_pallas=False, interpret=False):
     if use_pallas:
-        interp = (not _on_tpu()) if interpret is None else interpret
-        return _decode_pallas(q, k, v, kv_len, interpret=interp)
+        return _decode_pallas(q, k, v, kv_len, interpret=interpret)
     return ref.decode_attention_ref(q, k, v, kv_len)
 
 
-def ssd(x, dt, A, Bm, Cm, *, chunk=256, use_pallas=False, interpret=None):
+def ssd(x, dt, A, Bm, Cm, *, chunk=256, use_pallas=False, interpret=False):
     if use_pallas:
-        interp = (not _on_tpu()) if interpret is None else interpret
-        return _ssd_pallas(x, dt, A, Bm, Cm, chunk=chunk, interpret=interp)
+        return _ssd_pallas(x, dt, A, Bm, Cm, chunk=chunk, interpret=interpret)
     return ref.ssd_ref(x, dt, A, Bm, Cm)
 
 
-def gmm(x, w, *, use_pallas=False, interpret=None):
+def gmm(x, w, *, use_pallas=False, interpret=False):
     if use_pallas:
-        interp = (not _on_tpu()) if interpret is None else interpret
-        return _gmm_pallas(x, w, interpret=interp)
+        return _gmm_pallas(x, w, interpret=interpret)
     return ref.gmm_ref(x, w)
 
 
 def segment_sum(values, seg_ids, n_segments, *, use_pallas=False,
-                interpret=None):
+                interpret=False):
     if use_pallas:
-        interp = (not _on_tpu()) if interpret is None else interpret
-        return _segsum_pallas(values, seg_ids, n_segments, interpret=interp)
+        return _segsum_pallas(values, seg_ids, n_segments, interpret=interpret)
     return ref.segment_sum_ref(values, seg_ids, n_segments)

@@ -5,12 +5,18 @@
 The simulator core (``repro.core.simcore``) reduces replica occupancy
 to per-(node, app) buckets; each trial ``t`` carries its own placement,
 so the segment ids differ per row and a single one-hot matmul over the
-batch is impossible.  This kernel tiles the (T, R) grid and accumulates
-each tile's contribution as a chunked one-hot contraction into the
-(T, B) output — MXU-friendly on TPU, and exercised in interpret mode on
-the CPU CI container (see ``src/repro/kernels/README.md``).  On CPU the
-simulator's compute path stays the XLA sort-plan ``bucket_sum``; this
-kernel is the accelerator path plus the parity reference for it.
+batch is impossible.  This kernel tiles the (T, R) grid; for each row of
+a tile it builds the transposed one-hot ``(n_pad, r_block)`` from a
+sublane iota and contracts the row's values against it on the MXU
+(``values_row @ onehot.T``), accumulating into the row's (1, n_pad)
+output across the replica-axis grid steps.  Every slice is static, so
+Mosaic lowers it; the simulator's XLA sort-plan ``bucket_sum`` is the
+path off the TPU, and the tests run this kernel in interpret mode
+(see ``src/repro/kernels/README.md``).
+
+Mosaic has no float64: on the TPU the values are float32.  The
+simulator's 0/1 occupancy masks sum to at most R, which float32 holds
+exactly.  Interpret mode takes any float dtype.
 
 Segment ids outside ``[0, n_segments)`` contribute nothing (the one-hot
 never matches), which the padding below relies on.
@@ -22,48 +28,46 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["segment_sum"]
 
 _LANE = 128          # TPU lane width: last dims padded to a multiple
+
+# values_row (1, Rt) . onehot_t (n_pad, Rt), contracting the Rt axes
+_NT_DIMS = (((1,), (1,)), ((), ()))
 
 
 def _ceil_to(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _seg_kernel(vals_ref, ids_ref, out_ref, *, n_pad: int, r_chunk: int):
-    vals = vals_ref[...]                       # (Tt, Rt)
-    ids = ids_ref[...].astype(jnp.int32)
-    Tt, Rt = vals.shape
-    # chunk the replica axis so the (Tt, r_chunk, n_pad) one-hot stays
-    # inside VMEM; 1-D iota is unsupported on TPU, broadcast instead
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (Tt, r_chunk, n_pad), 2)
-
-    def body(i, acc):
-        v = jax.lax.dynamic_slice(vals, (0, i * r_chunk), (Tt, r_chunk))
-        s = jax.lax.dynamic_slice(ids, (0, i * r_chunk), (Tt, r_chunk))
-        hot = (s[:, :, None] == iota_b).astype(vals.dtype)
-        return acc + (hot * v[:, :, None]).sum(axis=1)
-
-    acc = jax.lax.fori_loop(0, Rt // r_chunk, body,
-                            jnp.zeros((Tt, n_pad), vals.dtype))
+def _seg_kernel(vals_ref, ids_ref, out_ref, *, n_pad: int):
+    t_block, r_block = vals_ref.shape
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
-    out_ref[...] += acc
+
+    # segment index down the sublanes; one (n_pad, r_block) one-hot per
+    # row keeps fast memory at n_pad * r_block words
+    iota_b = jax.lax.broadcasted_iota(jnp.int32, (n_pad, r_block), 0)
+    for t in range(t_block):
+        hot = (ids_ref[t:t + 1, :] == iota_b).astype(vals_ref.dtype)
+        row = jax.lax.dot_general(
+            vals_ref[t:t + 1, :], hot, _NT_DIMS,
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=out_ref.dtype)
+        out_ref[t:t + 1, :] += row
 
 
 def segment_sum(values, seg_ids, n_segments: int, *, t_block: int = 8,
-                r_block: int = _LANE, r_chunk: int = 8, interpret=None):
+                r_block: int = _LANE, interpret: bool = False):
     """Per-row bucket sums: (T, R) values + (T, R) int ids -> (T, B).
 
-    ``interpret=None`` auto-selects interpret mode off-TPU (the repo's
-    kernel idiom, see ``repro.kernels.ops``).
+    ``interpret=True`` runs the kernel in Pallas interpret mode (tests,
+    any backend); otherwise it is compiled for the TPU.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     values = jnp.asarray(values)
     seg_ids = jnp.asarray(seg_ids, jnp.int32)
     if values.shape != seg_ids.shape or values.ndim != 2:
@@ -76,14 +80,18 @@ def segment_sum(values, seg_ids, n_segments: int, *, t_block: int = 8,
         # pad with value 0 (id 0 then contributes nothing)
         values = jnp.pad(values, ((0, Tp - T), (0, Rp - R)))
         seg_ids = jnp.pad(seg_ids, ((0, Tp - T), (0, Rp - R)))
-    grid = (Tp // t_block, Rp // r_block)
     out = pl.pallas_call(
-        functools.partial(_seg_kernel, n_pad=n_pad, r_chunk=r_chunk),
-        grid=grid,
+        functools.partial(_seg_kernel, n_pad=n_pad),
+        grid=(Tp // t_block, Rp // r_block),
         in_specs=[pl.BlockSpec((t_block, r_block), lambda i, j: (i, j)),
                   pl.BlockSpec((t_block, r_block), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((t_block, n_pad), lambda i, j: (i, 0)),
+        # an int32 zero: under x64 a literal 0 is an i64 block index,
+        # which Mosaic refuses
+        out_specs=pl.BlockSpec((t_block, n_pad),
+                               lambda i, j: (i, jnp.int32(0))),
         out_shape=jax.ShapeDtypeStruct((Tp, n_pad), values.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(values, seg_ids)
     return out[:T, :n_segments]

@@ -22,18 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAS_PLTPU = False
-
-
-def _vmem(shape, dtype):
-    if _HAS_PLTPU:
-        return pltpu.VMEM(shape, dtype)
-    return pl.MemorySpace.ANY(shape, dtype)  # pragma: no cover
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(xd_ref, dA_ref, b_ref, c_ref, y_ref, state_out_ref, state_scr, *,
@@ -94,10 +83,8 @@ def ssd(x, dt, A, Bm, Cm, *, chunk: int = 256, interpret: bool = False):
     c2 = Cm.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(Bsz * G, L, N)
 
     kernel = functools.partial(_kernel, chunk=Q, n_chunks=nc)
-    kwargs = {}
-    if _HAS_PLTPU and not interpret:  # pragma: no cover (TPU only)
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"))
 
     def b_index(bh, j, rep=rep, G=G, H=H):
         b = bh // H
@@ -121,9 +108,9 @@ def ssd(x, dt, A, Bm, Cm, *, chunk: int = 256, interpret: bool = False):
             jax.ShapeDtypeStruct((Bsz * H, L, P), jnp.float32),
             jax.ShapeDtypeStruct((Bsz * H, P, N), jnp.float32),
         ],
-        scratch_shapes=[_vmem((P, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-        **kwargs,
+        compiler_params=params,
     )(xd, dA, b2, c2)
     y = y.reshape(Bsz, H, L, P).transpose(0, 2, 1, 3)
     state = state.reshape(Bsz, H, P, N)

@@ -20,7 +20,7 @@ import traceback
 import jax
 
 from repro.configs.base import SHAPES, available_archs, get_config, supported_shapes
-from repro.launch.hlo import collective_bytes, cost_dict
+from repro.launch.hlo import collective_bytes
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import build_cell, lower_cell
 
@@ -64,7 +64,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     lowered = lower_cell(cell)
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
-    cost = cost_dict(compiled)
+    cost = compiled.cost_analysis()
     rec["memory"] = _mem_dict(mem)
     rec["cost_full"] = {k: cost.get(k) for k in ("flops", "bytes accessed")}
     rec["collectives_full"] = collective_bytes(compiled.as_text())
@@ -84,7 +84,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                            overrides=_depth_override(base_cfg, n),
                            tcfg_overrides={"unroll_microbatches": True})
             comp = lower_cell(c).compile()
-            cost_n = cost_dict(comp)
+            cost_n = comp.cost_analysis()
             rec[f"cost_L{n}"] = {k: cost_n.get(k)
                                  for k in ("flops", "bytes accessed")}
             rec[f"collectives_L{n}"] = collective_bytes(comp.as_text())

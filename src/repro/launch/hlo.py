@@ -34,15 +34,6 @@ def _shape_bytes(dtype: str, dims: str) -> int:
     return n * _DTYPE_BYTES.get(dtype, 4)
 
 
-def cost_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` as a dict on every jax version (older
-    releases return a one-element list of dicts)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def collective_bytes(hlo_text: str) -> Dict[str, int]:
     """Sum per-device result bytes of every collective op, by op type."""
     totals: Dict[str, int] = defaultdict(int)

@@ -7,16 +7,10 @@ TPU v5e.  Multi-pod adds an outer "pod" axis (pure DP across DCN).
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:  # jax < 0.5: no explicit-sharding types; Auto is implied
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
     return jax.make_mesh(tuple(shape), tuple(axes),
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import (apply_mrope, apply_rope, dtype_of,
-                                 normal_init, opt_barrier, rmsnorm)
+                                 normal_init, rmsnorm)
 from repro.parallel.sharding import shard
 
 NEG_INF = -1e30
@@ -124,7 +124,7 @@ def decode_attention(q, k, v, kv_valid_len, block=None):
     def step(carry, xs):
         m, l, acc = carry
         bi, kblk, vblk = xs
-        kblk, vblk = opt_barrier((kblk, vblk))
+        kblk, vblk = jax.lax.optimization_barrier((kblk, vblk))
         s = jnp.einsum("bqkgd,bskd->bkgqs", qg, kblk,
                        preferred_element_type=jnp.float32) * scale
         s = shard(s, "batch", "kv_heads", None, None, "kv_seq")

@@ -18,25 +18,6 @@ def dtype_of(cfg) -> jnp.dtype:
     return jnp.dtype(cfg.dtype)
 
 
-# jax < 0.5 has no differentiation rule for optimization_barrier, so the
-# raw primitive cannot sit inside value_and_grad.  Identity in both
-# directions; the barrier still pins scheduling in each pass.
-@jax.custom_vjp
-def opt_barrier(x):
-    return jax.lax.optimization_barrier(x)
-
-
-def _opt_barrier_fwd(x):
-    return opt_barrier(x), None
-
-
-def _opt_barrier_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-opt_barrier.defvjp(_opt_barrier_fwd, _opt_barrier_bwd)
-
-
 def normal_init(key, shape, stddev, dtype):
     return (stddev * jax.random.truncated_normal(key, -2.0, 2.0, shape,
                                                  jnp.float32)).astype(dtype)

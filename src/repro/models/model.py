@@ -27,7 +27,7 @@ from repro.models.attention import (attention_decode, attention_fwd, init_attent
                                     init_mla, mla_decode, mla_fwd)
 from repro.models.common import (chunked_cross_entropy, dtype_of, embed_tokens,
                                  init_embedding, init_mlp, init_rmsnorm,
-                                 logits_from_hidden, mlp, opt_barrier, rmsnorm)
+                                 logits_from_hidden, mlp, rmsnorm)
 from repro.models.moe import init_moe, moe_ffn
 from repro.models.ssm import init_mamba2, mamba2_decode, mamba2_fwd
 from repro.parallel.sharding import shard
@@ -122,7 +122,7 @@ def _dec_layer_fwd(cfg, lp, h, positions):
     # constrained seq-sharded BEFORE the residual add so the row-parallel
     # all-reduce lowers to a reduce-scatter (attributed from HLO: the naive
     # placement gathered the f32 residual 3x per layer and used ARs).
-    a_in = opt_barrier(
+    a_in = jax.lax.optimization_barrier(
         shard(rmsnorm(lp["ln1"], h, cfg.norm_eps), "batch", "act_seq", None))
     if cfg.mla is not None:
         a, kv = mla_fwd(lp["attn"], cfg, a_in, positions, causal=cfg.causal)
@@ -131,7 +131,7 @@ def _dec_layer_fwd(cfg, lp, h, positions):
                               causal=cfg.causal)
     a = shard(a, "batch", "residual_seq", None)
     h = shard(h + a, "batch", "residual_seq", None)
-    f_in = opt_barrier(
+    f_in = jax.lax.optimization_barrier(
         shard(rmsnorm(lp["ln2"], h, cfg.norm_eps), "batch", "act_seq", None))
     if cfg.moe is not None:
         f, aux = moe_ffn(lp["ffn"], cfg, f_in, use_pallas=cfg.use_pallas)

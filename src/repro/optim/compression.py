@@ -61,19 +61,18 @@ def make_compressed_allreduce(mesh, axis_name: str = "pod"):
     """Returns fn(grads, residuals) -> (mean, residuals) running the
     error-feedback int8 reduction over ``axis_name`` via shard_map, with
     all other mesh axes untouched (grads replicated over them)."""
-    from jax.experimental.shard_map import shard_map
     axis_size = dict(zip(mesh.axis_names, mesh.devices.shape))[axis_name]
 
     def apply(grads, residuals):
         specs = jax.tree.map(lambda _: P(), grads)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(compressed_psum, axis_name=axis_name,
                               axis_size=axis_size),
             mesh=mesh,
             in_specs=(specs, specs),
             out_specs=(specs, specs),
-            check_rep=False)
+            check_vma=False)
         return fn(grads, residuals)
 
     return apply

@@ -16,7 +16,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -74,10 +73,10 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, params_stacked,
         # zeros, so a psum reconciles exactly
         return jax.lax.psum(outs, axis_name)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis_name), params_stacked),
                   P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return fn(params_stacked, x_micro)

@@ -19,6 +19,12 @@ import numpy as np
 from repro.models import model as M
 from repro.monitoring.metrics import MetricsStore, SimClock
 
+#: one jitted prefill and decode for the process: jit keys its compiled
+#: programs on the static (cfg, cache_len), so every engine serving the
+#: same model at the same max_seq shares one compilation of each
+jit_prefill = jax.jit(M.prefill, static_argnames=("cfg", "cache_len"))
+jit_decode = jax.jit(M.decode_step, static_argnames=("cfg",))
+
 
 @dataclass
 class Request:
@@ -57,10 +63,6 @@ class ServingEngine:
         # replica-seconds-busy side of the waste ledger
         self.active = True
         self.busy_s = 0.0
-
-        self._prefill = jax.jit(
-            lambda p, b: M.prefill(p, cfg, b, cache_len=max_seq))
-        self._decode = jax.jit(lambda p, c, t: M.decode_step(p, cfg, c, t))
 
     # ------------------------------------------------------------------
     def submit(self, req: Request):
@@ -105,7 +107,8 @@ class ServingEngine:
         if self.cfg.family == "encdec":
             batch["enc_frames"] = jnp.zeros((B, 8, self.cfg.d_model),
                                             jnp.bfloat16)
-        logits, cache = self._prefill(self.params, batch)
+        logits, cache = jit_prefill(self.params, cfg=self.cfg, batch=batch,
+                                    cache_len=self.max_seq)
         n_new = max(r.max_new_tokens for r in wave)
         outs = [[] for _ in range(B)]
         tok = np.asarray(jnp.argmax(logits[:, : self.cfg.vocab_size], -1),
@@ -113,8 +116,8 @@ class ServingEngine:
         for i in range(B):
             outs[i].append(tok[i])
         for _ in range(n_new - 1):
-            logits, cache = self._decode(self.params,
-                                         cache, jnp.asarray(tok[:, None]))
+            logits, cache = jit_decode(self.params, cfg=self.cfg, cache=cache,
+                                       tokens=jnp.asarray(tok[:, None]))
             tok = np.asarray(jnp.argmax(logits[:, : self.cfg.vocab_size], -1),
                              np.int32)
             for i in range(B):

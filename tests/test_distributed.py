@@ -107,7 +107,7 @@ def test_reduced_dryrun_multipod_lowering():
         import jax
         from repro.launch.mesh import make_mesh
         from repro.launch.specs import build_cell, lower_cell
-        from repro.launch.hlo import collective_bytes, cost_dict
+        from repro.launch.hlo import collective_bytes
         mesh = make_mesh((2, 2, 4), ("pod", "data", "model"))
         cell = build_cell("deepseek-67b", "train_4k", mesh,
                           overrides={"num_layers": 2, "d_model": 256,
@@ -116,7 +116,7 @@ def test_reduced_dryrun_multipod_lowering():
                                      "vocab_size": 1024})
         compiled = lower_cell(cell).compile()
         mem = compiled.memory_analysis()
-        cost = cost_dict(compiled)
+        cost = compiled.cost_analysis()
         assert cost["flops"] > 0
         colls = collective_bytes(compiled.as_text())
         assert colls["_total"] > 0, colls

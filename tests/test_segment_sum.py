@@ -20,7 +20,7 @@ KEY = jax.random.PRNGKey(0)
     (1, 1, 1),         # degenerate
 ])
 def test_segment_sum_sweep(dtype, T, R, B):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         vals = jax.random.normal(KEY, (T, R), jnp.float32).astype(dtype)
         ids = jax.random.randint(jax.random.fold_in(KEY, 1), (T, R), 0, B)
         out = segment_sum(vals, ids, B, interpret=True)
@@ -33,7 +33,7 @@ def test_segment_sum_sweep(dtype, T, R, B):
 def test_segment_sum_integer_counts_exact():
     """The simulator feeds 0/1 occupancy masks: the kernel's sums must
     be integer-exact, not merely allclose."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         vals = (jax.random.uniform(KEY, (5, 97)) < 0.5).astype(jnp.float64)
         ids = jax.random.randint(jax.random.fold_in(KEY, 1), (5, 97),
                                  0, 13)
@@ -42,8 +42,26 @@ def test_segment_sum_integer_counts_exact():
         np.testing.assert_array_equal(out, want)
 
 
+@pytest.mark.parametrize("p_busy,B", [
+    (0.5, 1250),       # the recount: N * A = 250 * 5 (node, app) buckets
+    (1.0, 3),          # every replica busy, up to R = 1000 per bucket
+])
+def test_segment_sum_f32_counts_exact_at_recount_shape(p_busy, B):
+    """The simulator hands the kernel float32 0/1 masks at (T, R) =
+    (256, 1000), under x64: counts up to R are exact in float32."""
+    with jax.enable_x64():
+        vals = (jax.random.uniform(KEY, (256, 1000)) < p_busy) \
+            .astype(jnp.float32)
+        ids = jax.random.randint(jax.random.fold_in(KEY, 1), (256, 1000),
+                                 0, B)
+        out = segment_sum(vals, ids, B, interpret=True)
+        assert out.dtype == jnp.float32
+        np.testing.assert_array_equal(
+            np.asarray(out), np.asarray(ref.segment_sum_ref(vals, ids, B)))
+
+
 def test_segment_sum_out_of_range_ids_dropped():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         vals = jnp.ones((2, 10), jnp.float64)
         ids = jnp.array([[0, 1, 2, 3, 4, 5, 6, 7, 8, 9]] * 2)
         out = np.asarray(segment_sum(vals, ids, 4, interpret=True))
@@ -58,7 +76,7 @@ def test_segment_sum_rejects_mismatched_shapes():
 
 
 def test_ops_dispatch_matches_ref():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         vals = jax.random.normal(KEY, (4, 33), jnp.float64)
         ids = jax.random.randint(jax.random.fold_in(KEY, 1), (4, 33),
                                  0, 7)

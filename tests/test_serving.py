@@ -41,6 +41,24 @@ def test_engine_serves_wave(tiny_setup):
         assert r.rtt is not None and r.rtt >= 0
 
 
+def test_engines_share_one_compiled_prefill_and_decode(tiny_setup):
+    """Replicas of one model at one max_seq compile prefill and decode
+    once between them, not once per engine."""
+    from repro.serving.engine import jit_decode, jit_prefill
+
+    cfg, params = tiny_setup
+    engines = [ServingEngine(cfg, params, max_batch=2, max_seq=48,
+                             clock=SimClock()) for _ in range(3)]
+    rng = np.random.default_rng(0)
+    before = jit_prefill._cache_size(), jit_decode._cache_size()
+    for eng in engines:
+        for r in _reqs(2, rng):
+            eng.submit(r)
+        eng.step_wave()
+    assert (jit_prefill._cache_size() - before[0],
+            jit_decode._cache_size() - before[1]) == (1, 1)
+
+
 def test_engine_exports_metrics(tiny_setup):
     cfg, params = tiny_setup
     clock = SimClock()
