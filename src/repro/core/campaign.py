@@ -44,10 +44,11 @@ from repro.core.telemetry import PhaseTimer
 DEFAULT_POLICIES = ("perf_aware", "least_conn", "round_robin", "random")
 
 #: wall-time per phase of the most recent :func:`run_scenario` call
-#: ("build" + one "run:<policy>" entry per lockstep pass), refreshed per
-#: call.  The phases double as ``jax.profiler`` trace annotations (see
-#: :class:`~repro.core.telemetry.PhaseTimer`); ``bench_campaign`` folds
-#: this breakdown into the campaign artifact.
+#: ("build" + one "run:<policy>" entry per lockstep pass, with its steps
+#: as "run:<policy>/<step>"), refreshed per call.  The phases double as
+#: ``jax.profiler`` trace annotations (see
+#: :func:`~repro.core.telemetry.span`); ``bench_campaign`` folds this
+#: breakdown into the campaign artifact.
 LAST_PHASES: Dict[str, float] = {}
 
 #: summary stats aggregated per seed (means over that seed's trials);

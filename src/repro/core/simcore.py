@@ -78,7 +78,8 @@ from repro.core.resilience import ResilienceConfig
 from repro.core.rng import rng_from_key, rng_key, rng_seed, rng_stream
 from repro.core.simulator import SimConfig, _build_cluster, _Cluster, _Metrics
 from repro.core.telemetry import (DISP_FAIL_FAST, DISP_SERVED, DISP_SHED,
-                                  DISP_TIMEOUT, TRACE_FIELDS, trace_block)
+                                  DISP_TIMEOUT, TRACE_FIELDS, span,
+                                  trace_block)
 from repro.monitoring.metrics import PeriodicRefresh
 
 __all__ = ["supports", "run_compiled", "run_sim_compiled",
@@ -1058,6 +1059,7 @@ def _build_kernel(st: _Static):
                 return (cnt_a < st.min_count) \
                     | (acc >= st.fallback_threshold)
 
+        @jax.named_scope("sim_step")
         def step(cr, x):
             busy = cr["busy"]
             j, a, now = x["j"], x["app"], x["t"]
@@ -1865,6 +1867,20 @@ def _pad_trials(tree, T, Tp):
     return out
 
 
+def _put(*trees):
+    """The host trees on the device, waited for (the kernel waits for
+    them anyway): the ``put`` span."""
+    with span("put"):
+        return jax.block_until_ready(
+            [{k: jnp.asarray(v) for k, v in t.items()} for t in trees])
+
+
+def _fetch(*trees):
+    """The device trees back on the host: the ``fetch`` span."""
+    with span("fetch"):
+        return [{k: np.asarray(v) for k, v in t.items()} for t in trees]
+
+
 def _execute(st, consts, xs, carry0, force_single=False):
     ndev = jax.device_count()
     T = carry0["busy"].shape[0]
@@ -1875,16 +1891,14 @@ def _execute(st, consts, xs, carry0, force_single=False):
             consts = _pad_trials(consts, T, Tp)
             xs = _pad_trials(xs, T, Tp)
             carry0 = _pad_trials(carry0, T, Tp)
-        cj = {k: jnp.asarray(v) for k, v in consts.items()}
-        xj = {k: jnp.asarray(v) for k, v in xs.items()}
-        crj = {k: jnp.asarray(v) for k, v in carry0.items()}
+        cj, xj, crj = _put(consts, xs, carry0)
         if use_shard:
             fn = _get_fn(st, "shard", ndev, (cj, xj, crj, _ys_keys(st)))
         else:
             fn = _get_fn(st, "jit", 1)
-        final, ys = fn(cj, xj, crj)
-        final = {k: np.asarray(v) for k, v in final.items()}
-        ys = {k: np.asarray(v) for k, v in ys.items()}
+        with span("kernel"):
+            out = jax.block_until_ready(fn(cj, xj, crj))
+        final, ys = _fetch(*out)
     if Tp != T:
         def _cut(tree):
             out = {}
@@ -2053,9 +2067,11 @@ def run_compiled(cluster: _Cluster, policy: str, *, seed_blocks=None,
     reason = supports(cluster.cfg, policy)
     if reason is not None:
         raise ValueError(f"simcore cannot run this config: {reason}")
-    st, consts, xs, carry0, aux = _lower(cluster, policy, seed_blocks)
+    with span("lower"):
+        st, consts, xs, carry0, aux = _lower(cluster, policy, seed_blocks)
     final, ys, backend = _execute(st, consts, xs, carry0, force_single)
-    return _summarize(cluster, st, final, ys, aux, backend)
+    with span("summarize"):
+        return _summarize(cluster, st, final, ys, aux, backend)
 
 
 def prepare_compiled(cluster: _Cluster, policy: str, *,
@@ -2072,19 +2088,19 @@ def prepare_compiled(cluster: _Cluster, policy: str, *,
     reason = supports(cluster.cfg, policy)
     if reason is not None:
         raise ValueError(f"simcore cannot run this config: {reason}")
-    st, consts, xs, carry0, aux = _lower(cluster, policy, seed_blocks)
+    with span("lower"):
+        st, consts, xs, carry0, aux = _lower(cluster, policy, seed_blocks)
     with jax.enable_x64():
-        cj = {k: jnp.asarray(v) for k, v in consts.items()}
-        xj = {k: jnp.asarray(v) for k, v in xs.items()}
-        crj = {k: jnp.asarray(v) for k, v in carry0.items()}
+        cj, xj, crj = _put(consts, xs, carry0)
         fn = _get_fn(st, "jit", 1)
 
     def run() -> Dict[str, np.ndarray]:
         with jax.enable_x64():
-            final, ys = fn(cj, xj, crj)
-            final_np = {k: np.asarray(v) for k, v in final.items()}
-            ys_np = {k: np.asarray(v) for k, v in ys.items()}
-        return _summarize(cluster, st, final_np, ys_np, aux, "jit")
+            with span("kernel"):
+                out = jax.block_until_ready(fn(cj, xj, crj))
+            final_np, ys_np = _fetch(*out)
+        with span("summarize"):
+            return _summarize(cluster, st, final_np, ys_np, aux, "jit")
 
     return run
 

@@ -29,15 +29,16 @@ The same schema is emitted by all three execution paths:
   the columnar ``MetricsStore``.
 
 This module imports only numpy so the serial path and the router stay
-jax-free; :class:`PhaseTimer` imports ``jax.profiler`` lazily.
+jax-free; :func:`span` imports ``jax.profiler`` at its first use.
 """
 from __future__ import annotations
 
+import functools
 import math
+import threading
 import time
-from contextlib import contextmanager
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -348,34 +349,103 @@ class MetricsRegistry:
 
 
 # ---------------------------------------------------------------------------
-# Phase timing (campaign runner)
+# Spans: campaign phases, the compiled pass's steps, the served path
 # ---------------------------------------------------------------------------
 
 class PhaseTimer:
-    """Named wall-time accumulator whose phases double as
-    ``jax.profiler.TraceAnnotation`` ranges when jax is importable, so
-    campaign phases show up in profiler traces; degrades to plain
-    timing otherwise."""
+    """Wall time and count per span name, for the spans it owns.
+
+    ``phase(name)`` opens a span owned by this timer; every span opened
+    inside it (:func:`span`) is named ``<phase>/<child>`` and recorded
+    here too, so a caller's timer collects the steps of the code it
+    calls.  A phase's own name is never prefixed, so a timer's top-level
+    names do not depend on what its caller has open."""
 
     def __init__(self):
         self.wall: Dict[str, float] = {}
+        self.count: Dict[str, int] = {}
+        self._lock = threading.Lock()
 
-    @staticmethod
-    def _annotation(name: str):
-        try:  # pragma: no cover - depends on jax availability
-            from jax.profiler import TraceAnnotation
-            return TraceAnnotation(name)
-        except Exception:
-            from contextlib import nullcontext
-            return nullcontext()
-
-    @contextmanager
     def phase(self, name: str):
-        t0 = time.perf_counter()
-        with self._annotation(name):
-            yield
-        self.wall[name] = self.wall.get(name, 0.0) + (
-            time.perf_counter() - t0)
+        return span(name, timer=self)
+
+    def add(self, name: str, seconds: float):
+        with self._lock:
+            self.wall[name] = self.wall.get(name, 0.0) + seconds
+            self.count[name] = self.count.get(name, 0) + 1
 
     def summary(self) -> Dict[str, float]:
         return dict(sorted(self.wall.items()))
+
+
+#: totals of the spans that no timer owns (opened outside any phase)
+_PROCESS = PhaseTimer()
+#: ``open``: this thread's open spans, outermost first, as (full name,
+#: owning timer), so that spans of different threads never nest
+_LOCAL = threading.local()
+#: ``jax.profiler.TraceAnnotation`` (a no-op context without jax),
+#: imported at the first span so that this module stays jax-free
+_ANNOTATION = None
+
+
+def _annotation(name: str):
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation as _ANNOTATION
+        except ImportError:  # pragma: no cover - jax-free installs
+            from contextlib import nullcontext as _ANNOTATION
+    return _ANNOTATION(name)
+
+
+class span:
+    """A timed host-side span, also a profiler ``TraceAnnotation`` of its
+    full name, so that a traced run places it on the device trace's
+    clock.  Inside another span it is named ``<parent>/<name>`` and is
+    recorded by the parent's timer; outside every timer it goes to the
+    process-wide totals (:func:`snapshot`).  A context manager entered
+    once, or a decorator that opens a new one per call.  Host code only:
+    never open one inside traced (jitted) code."""
+
+    __slots__ = ("name", "timer", "_ann", "_t0")
+
+    def __init__(self, name: str, timer: Optional[PhaseTimer] = None):
+        self.name = name
+        self.timer = timer
+
+    def __enter__(self):
+        stack = _LOCAL.__dict__.setdefault("open", [])
+        if self.timer is None:
+            if stack:
+                parent, self.timer = stack[-1]
+                self.name = f"{parent}/{self.name}"
+            else:
+                self.timer = _PROCESS
+        stack.append((self.name, self.timer))
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        self.timer.add(self.name, dt)
+        _LOCAL.open.pop()
+
+    def __call__(self, fn):
+        name, timer = self.name, self.timer
+
+        @functools.wraps(fn)
+        def spanned(*args, **kwargs):
+            with span(name, timer):
+                return fn(*args, **kwargs)
+        return spanned
+
+
+def snapshot() -> Dict[str, Tuple[int, float]]:
+    """(count, seconds) by full name of every span no timer owned, since
+    the process started; the difference of two snapshots covers what
+    ran between them."""
+    with _PROCESS._lock:
+        return {k: (_PROCESS.count[k], _PROCESS.wall[k])
+                for k in sorted(_PROCESS.wall)}

@@ -93,5 +93,6 @@ def segment_sum(values, seg_ids, n_segments: int, *, t_block: int = 8,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="segment_sum",
     )(values, seg_ids)
     return out[:T, :n_segments]

@@ -372,6 +372,7 @@ def train_forward(params, cfg, batch):
     raise ValueError(cfg.family)
 
 
+@jax.named_scope("prefill")
 def prefill(params, cfg, batch, cache_len=None):
     if cfg.family in _DEC_FAMILIES:
         return _dec_prefill(params, cfg, batch, cache_len)
@@ -382,6 +383,7 @@ def prefill(params, cfg, batch, cache_len=None):
     raise ValueError(cfg.family)
 
 
+@jax.named_scope("decode_step")
 def decode_step(params, cfg, cache, tokens):
     if cfg.family in _DEC_FAMILIES:
         return _dec_decode(params, cfg, cache, tokens)

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.telemetry import span
 from repro.models import model as M
 from repro.monitoring.metrics import MetricsStore, SimClock
 
@@ -32,6 +33,7 @@ class Request:
     tokens: np.ndarray              # (prompt_len,)
     max_new_tokens: int = 16
     t_enqueue: float = 0.0
+    t_start: Optional[float] = None     # when a wave took it
     t_done: Optional[float] = None
     output: Optional[np.ndarray] = None
 
@@ -70,6 +72,7 @@ class ServingEngine:
         self.queue.append(req)
         self._export()
 
+    @span("scrape")
     def _export(self):
         active = 0
         self.store.scrape({
@@ -94,6 +97,8 @@ class ServingEngine:
         t_wave0 = self.clock.now()
         wave = self.queue[: self.max_batch]
         self.queue = self.queue[self.max_batch:]
+        for r in wave:
+            r.t_start = t_wave0
         B = len(wave)
         plen = max(len(r.tokens) for r in wave)
         toks = np.zeros((B, plen), np.int32)
@@ -107,24 +112,29 @@ class ServingEngine:
         if self.cfg.family == "encdec":
             batch["enc_frames"] = jnp.zeros((B, 8, self.cfg.d_model),
                                             jnp.bfloat16)
-        logits, cache = jit_prefill(self.params, cfg=self.cfg, batch=batch,
-                                    cache_len=self.max_seq)
+        with span("prefill"):               # dispatch until on the host
+            logits, cache = jit_prefill(self.params, cfg=self.cfg,
+                                        batch=batch, cache_len=self.max_seq)
+            tok = np.asarray(
+                jnp.argmax(logits[:, : self.cfg.vocab_size], -1), np.int32)
         n_new = max(r.max_new_tokens for r in wave)
         outs = [[] for _ in range(B)]
-        tok = np.asarray(jnp.argmax(logits[:, : self.cfg.vocab_size], -1),
-                         np.int32)
         for i in range(B):
             outs[i].append(tok[i])
         for _ in range(n_new - 1):
-            logits, cache = jit_decode(self.params, cfg=self.cfg, cache=cache,
-                                       tokens=jnp.asarray(tok[:, None]))
-            tok = np.asarray(jnp.argmax(logits[:, : self.cfg.vocab_size], -1),
-                             np.int32)
+            with span("decode"):            # dispatch until on the host
+                logits, cache = jit_decode(self.params, cfg=self.cfg,
+                                           cache=cache,
+                                           tokens=jnp.asarray(tok[:, None]))
+                tok = np.asarray(
+                    jnp.argmax(logits[:, : self.cfg.vocab_size], -1),
+                    np.int32)
             for i in range(B):
                 outs[i].append(tok[i])
             self._tok_count += B
             if self.slowdown:
-                self.clock.advance(self.slowdown)
+                with span("slowdown"):
+                    self.clock.advance(self.slowdown)
             self._export()
         jax.block_until_ready(logits)
         now = self.clock.now()

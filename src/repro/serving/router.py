@@ -61,7 +61,7 @@ from repro.core.prediction_plane import PredictionPlane
 from repro.core.resilience import BreakerBoard, ResilienceConfig
 from repro.core.telemetry import (DISP_SERVED, DISP_SHED, DISP_TIMEOUT,
                                   MetricsRegistry, TRACE_FIELDS, compose_row,
-                                  trace_block)
+                                  span, trace_block)
 from repro.serving.engine import Request, ServingEngine
 
 
@@ -206,7 +206,8 @@ class MorpheusRouter:
         if needs_pred is None:
             needs_pred = isinstance(self.policy, PerfAware)
         if needs_pred:
-            predicted = self._predicted_rtts()
+            with span("predict"):
+                predicted = self._predicted_rtts()
             waves = np.ceil(queue
                             / np.array([r.max_batch for r in self.replicas]))
             wait_est = predicted * waves
@@ -220,6 +221,7 @@ class MorpheusRouter:
                             predicted=None if predicted is None
                             else predicted[None, :], active=active)
 
+    @span("route")
     def route(self, req: Request) -> int:
         """Route one request; returns the replica index, or -1 when the
         capacity plane's admission control sheds it (the request is
@@ -264,20 +266,22 @@ class MorpheusRouter:
         # pick == argmin(mask_inactive(score)) + update, spelled out so
         # the flight recorder sees the scores the decision was made on
         # (bit-identical to Policy.pick — same single score() call)
-        if fell_back:
-            self.fallbacks += 1
-            self.m_fallbacks.inc()
-            reactive = ClusterState(
-                now=0.0, busy_until=np.zeros((1, len(self.replicas))),
-                queue_depth=self._queue_proxy()[None, :],
-                active=state.active)
-            tr_scores = self._fallback_policy.score(reactive)
-            i = int(np.argmin(reactive.mask_inactive(tr_scores), axis=1)[0])
-            self._fallback_policy.update(reactive, np.array([i]))
-        else:
-            tr_scores = self.policy.score(state)
-            i = int(np.argmin(state.mask_inactive(tr_scores), axis=1)[0])
-            self.policy.update(state, np.array([i]))
+        with span("pick"):
+            if fell_back:
+                self.fallbacks += 1
+                self.m_fallbacks.inc()
+                reactive = ClusterState(
+                    now=0.0, busy_until=np.zeros((1, len(self.replicas))),
+                    queue_depth=self._queue_proxy()[None, :],
+                    active=state.active)
+                tr_scores = self._fallback_policy.score(reactive)
+                i = int(np.argmin(reactive.mask_inactive(tr_scores),
+                                  axis=1)[0])
+                self._fallback_policy.update(reactive, np.array([i]))
+            else:
+                tr_scores = self.policy.score(state)
+                i = int(np.argmin(state.mask_inactive(tr_scores), axis=1)[0])
+                self.policy.update(state, np.array([i]))
         self.replicas[i].submit(req)
         self.routed.append(i)
         self.m_requests.inc()
@@ -287,7 +291,6 @@ class MorpheusRouter:
             "predicted": (float(state.predicted[0, i])
                           if state.predicted is not None else np.nan),
             "score": float(tr_scores[0, i]),
-            "queue_wait": float(state.busy_until[0, i]),
         }
         self._trace_seq += 1
         if self.resilience is not None and self.resilience.client_side:
@@ -318,6 +321,7 @@ class MorpheusRouter:
         return i
 
     # ------------------------------------------------------------------
+    @span("drain")
     def drain(self) -> List[Request]:
         """Serve every queued request to completion (round over replicas).
 
@@ -363,17 +367,17 @@ class MorpheusRouter:
                     and id(r) not in self._timeout_ids]
         self._hedge_pairs.clear()
         # finalize served trace rows (post hedge reconciliation, so the
-        # response is the winning completion).  The router can't observe
-        # the engine's internal queue/service split, so the pick-time
-        # wait estimate (clamped to the response) stands in for
-        # queue_wait and service_base absorbs the rest — the sum rule
+        # response is the winning completion).  queue_wait is the
+        # measured wait from enqueue to the wave that took the request,
+        # service_base the rest of its own attempt, so the sum rule
         # holds by construction: qw + base - hedge_s == response.
         for rid in [k for k, v in self._trace_open.items()
                     if v["req"].t_done is not None]:
             row = self._trace_open.pop(rid)
-            resp = float(row["req"].rtt)
+            req = row["req"]
+            resp = float(req.rtt)
             hs = float(self._hedge_saved.pop(rid, 0.0))
-            qw = min(row["queue_wait"], resp)
+            qw = float(req.t_start - req.t_enqueue)
             self.m_inflight.dec()
             self.m_rtt.observe(resp)
             self._trace_done.append((row["seq"], compose_row(
