@@ -10,10 +10,10 @@ the headline large configuration (256 stacked trials x 1000 replicas x
 - **cell** — the end-to-end cost of one (scenario, policy) campaign
   cell on FRESH per-cluster engine state, which is what every
   ``run_scenario(backend=...)`` call pays: the serial engine builds its
-  lazy per-app ``_AppPrep`` tables (including the eager per-app
-  ``z_pred`` slices — the dominant cost at this scale), the compiled
-  engine re-lowers (``_lower``).  The shared cluster build and the
-  one-time XLA compilation are excluded from both sides.
+  lazy per-app ``_AppPrep`` tables, the compiled engine re-lowers
+  (``_lower``).  The shared cluster build, the cluster's prediction
+  noise block (drawn once, at its first read) and the one-time XLA
+  compilation are excluded from both sides.
 - **warm us/step** — steady-state per-step cost with every per-cluster
   cache hot (the marginal cost of one more pass over the same stacked
   cluster).  On ONE CPU core numpy and XLA retire this work at a

@@ -17,7 +17,9 @@ pays it once per (scenario, policy):
   via the per-scenario ``stream_seed`` (paired comparison across seeds).
 
 Batched and serial execution are numerically interchangeable: stacking
-concatenates exactly the arrays the serial runs would use, stateful
+concatenates exactly the arrays the serial runs would use (and the
+per-seed records of the prediction noise, which a pass draws only when
+it reads them, bit for bit as the serial runs would), stateful
 policies (the RR cursor) keep per-trial state, and ``RandomChoice`` is
 handed per-seed generator blocks (``seed_blocks``) so each block draws
 what its serial run would.  The closed-loop scenarios ride the same
@@ -65,7 +67,8 @@ def _resolve(scenario) -> ScenarioSpec:
 
 
 def stack_clusters(clusters: Sequence[_Cluster]) -> _Cluster:
-    """Concatenate per-seed clusters along the trial axis.
+    """Concatenate per-seed clusters along the trial axis.  The deferred
+    prediction noise stacks as its list of per-seed blocks: no array.
 
     Shared-stream precondition: every cluster must carry the same
     request sequence (app ids and arrival times) — the lockstep pass
@@ -114,7 +117,9 @@ def stack_clusters(clusters: Sequence[_Cluster]) -> _Cluster:
         cpu_req=c0.cpu_req, mem_req=c0.mem_req,
         imat=imat, node_of=cat("node_of"), accel=cat("accel"),
         req_app=c0.req_app, req_t=c0.req_t,
-        z_rtt=cat("z_rtt"), z_pred=cat("z_pred"), failed_node=failed,
+        z_rtt=cat("z_rtt"),
+        pred_noise=sum((c.pred_noise for c in clusters), ()),
+        failed_node=failed,
         imat_post=imat_post, accel_post=accel_post,
         mean_rtt_post=c0.mean_rtt_post, preempted_node=preempted,
         gray_rep=gray_rep, group_rep=group_rep, z_jitter=z_jitter)

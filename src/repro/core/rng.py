@@ -65,6 +65,15 @@ def rng_from_key(key) -> np.random.Generator:
     return np.random.default_rng(key)
 
 
+def rng_resume(state: dict) -> np.random.Generator:
+    """A ``Generator`` at a saved ``bit_generator.state``: it draws
+    exactly what the saved generator drew next (the deferred prediction
+    noise, ``simulator._NoiseBlock``)."""
+    bit_gen = getattr(np.random, state["bit_generator"])()
+    bit_gen.state = state
+    return np.random.Generator(bit_gen)
+
+
 def rng_key(seed: int, name: str):
     """A jax PRNG key on the named stream (lazy jax import — numpy-only
     consumers of this module never pay for it).

@@ -35,9 +35,10 @@ kernel must agree with it to <= 1e-5 relative on every summary stat for
 every supported config (``tests/test_simcore.py`` gates all registered
 scenarios).  All float state runs under ``jax.enable_x64``
 so the only divergence from the numpy path is libm/XLA ulp noise.
-Pre-drawn noise (``_Cluster.z_rtt`` / ``z_pred`` / the RandomChoice
-stream) is fed in as scan inputs, so compiled and serial runs consume
-bit-identical randomness.
+Pre-drawn noise (``_Cluster.z_rtt``, the prediction noise's candidate
+block ``_Cluster.z_pred_block``, the RandomChoice stream) is fed in as
+scan inputs, so compiled and serial runs consume bit-identical
+randomness.
 
 **Dispatch**: with multiple devices and a supported config the trial
 axis is sharded via ``shard_map`` (trials are embarrassingly parallel
@@ -427,13 +428,9 @@ def _lower(cluster: _Cluster, policy: str, seed_blocks=None):
     if not st.native_noise:
         xs["z"] = np.ascontiguousarray(cluster.z_rtt.T)        # (J, T)
         if st.needs_pred and not st.closed_loop:
-            # pre-gather each step's candidate block: (J, T, K), the
-            # only slice of z_pred the kernel ever reads
-            cand_idx = req_app.astype(np.int64)[:, None] * K \
-                + np.arange(K)[None, :]                        # (J, K)
-            xs["zp"] = np.take_along_axis(
-                cluster.z_pred.transpose(1, 0, 2),
-                cand_idx[:, None, :], axis=2)                  # (J, T, K)
+            # each step's candidate block: the only slice of the
+            # prediction noise the kernel ever reads
+            xs["zp"] = cluster.z_pred_block                    # (J, T, K)
         if st.policy == "random":
             xs["draw"] = _policy_draws(J, T, K,
                                        rng_seed(cfg.seed, "policy"),

@@ -69,6 +69,7 @@ from __future__ import annotations
 
 import warnings
 from dataclasses import dataclass, replace
+from functools import cached_property
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -81,10 +82,10 @@ from repro.core.capacity import (CapacityConfig, CapacityController,
 from repro.core.online import OnlineFleet
 from repro.core.resilience import (backoff_delay, BreakerBoard,
                                    ResilienceConfig)
-from repro.core.rng import rng_seed, rng_stream
+from repro.core.rng import rng_resume, rng_seed, rng_stream
 from repro.core.telemetry import (compose_row, DISP_FAIL_FAST, DISP_SERVED,
                                   DISP_SHED, DISP_TIMEOUT, FlightRecorder,
-                                  TraceConfig)
+                                  span, TraceConfig)
 from repro.monitoring.metrics import PeriodicRefresh
 
 # SPA app profiles: (mean RTT s, cpu cores/req, mem GB/req) — scaled from
@@ -239,13 +240,25 @@ class _AppPrep:
     cand_flat: np.ndarray     # (T*C,) flat (trial, node) index per candidate
     weight: np.ndarray        # (T, R) interference weight per busy replica
     speed: np.ndarray         # (T, C) 1 + accel of each candidate's node
-    z_pred: np.ndarray        # (T, J, C) prediction noise, pre-gathered
     log_rbar: float           # log of the app's mean RTT
+
+
+@dataclass(frozen=True)
+class _NoiseBlock:
+    """One seed's prediction noise, not yet drawn: the state of its
+    generator where the (trials, J, R) draw starts."""
+    state: dict
+    trials: int
 
 
 @dataclass
 class _Cluster:
     """Static per-run arrays: topology, request stream, pre-drawn noise.
+
+    The prediction noise is the exception: ``pred_noise`` records where
+    each seed's block lies in its generator's stream, and
+    :attr:`z_pred_block` draws the candidates' part of it at its first
+    read, bit for bit what an eager draw would give.
 
     ``imat`` is (A, A) for a single-seed cluster; the campaign's stacked
     clusters carry a per-trial (T, A, A) matrix because each seed drew
@@ -264,7 +277,7 @@ class _Cluster:
     req_app: np.ndarray       # (J,) app index per request
     req_t: np.ndarray         # (J,) arrival time per request
     z_rtt: np.ndarray         # (T, J) RTT noise
-    z_pred: np.ndarray        # (T, J, R) prediction noise
+    pred_noise: Tuple[_NoiseBlock, ...]  # (T, J, R) noise, per-seed blocks
     failed_node: Optional[np.ndarray] = None   # (T,) churn target
     imat_post: Optional[np.ndarray] = None     # post-drift interference
     accel_post: Optional[np.ndarray] = None    # post-drift node speeds
@@ -292,6 +305,32 @@ class _Cluster:
         # of allocating two fresh arrays per event
         self._busy_mask = np.empty(self.node_of.shape, bool)
         self._busy_w = np.empty(self.node_of.shape, float)
+
+    @cached_property
+    def z_pred_block(self) -> np.ndarray:
+        """(J, T, K) prediction noise of each request's K candidates,
+        drawn at its first read and kept: bit for bit the candidate
+        slice of an eager (T, J, R) draw, made a trial at a time in one
+        (J, R) scratch buffer, so the full tensor never exists.  Row
+        ``j`` is what both engines read for request ``j``; a pass that
+        scores no predictions never draws it."""
+        with span("pred_noise"):
+            J, T, A = len(self.req_t), len(self.node_of), len(self.mean_rtt)
+            R = len(self.app_of)
+            out = np.empty((J, T, R // A))
+            scratch = np.empty((J, R))
+            # app_of is np.repeat(arange(A), K): app a's candidates are
+            # the contiguous replicas a*K .. a*K + K - 1
+            by_app = scratch.reshape(J, A, R // A)
+            rows = np.arange(J)
+            t = 0
+            for b in self.pred_noise:
+                rng = rng_resume(b.state)
+                for _ in range(b.trials):
+                    rng.standard_normal(out=scratch)
+                    out[:, t, :] = by_app[rows, self.req_app]
+                    t += 1
+            return out
 
     def in_drift(self, now: float) -> bool:
         return self.cfg.t_drift is not None and now >= self.cfg.t_drift
@@ -321,7 +360,6 @@ class _Cluster:
                            + nodes).ravel(),
                 weight=weight,
                 speed=1.0 + accel[trial[:, None], nodes],
-                z_pred=np.ascontiguousarray(self.z_pred[:, :, cand]),
                 log_rbar=float(np.log(mean_rtt[a])))
             self._prep[key] = prep
         return prep
@@ -405,7 +443,9 @@ def _build_cluster(cfg: SimConfig) -> _Cluster:
     req_app = stream_rng.integers(0, A, size=cfg.n_requests)
     req_t = _arrival_times(cfg, stream_rng)
     z_rtt = noise_rng.standard_normal((T, cfg.n_requests))
-    z_pred = noise_rng.standard_normal((T, cfg.n_requests, R))
+    # the (T, J, R) prediction noise is this generator's last draw: keep
+    # where it starts, and draw it only when a pass reads it
+    pred_noise = (_NoiseBlock(noise_rng.bit_generator.state, T),)
     failed_node = None
     if cfg.churn is not None:
         failed_node = rng_stream(cfg.seed, "churn").integers(
@@ -466,7 +506,7 @@ def _build_cluster(cfg: SimConfig) -> _Cluster:
         cpu_req=np.array([APPS[a][1] for a in cfg.apps]),
         mem_req=np.array([APPS[a][2] for a in cfg.apps]),
         imat=imat, node_of=node_of, accel=accel,
-        req_app=req_app, req_t=req_t, z_rtt=z_rtt, z_pred=z_pred,
+        req_app=req_app, req_t=req_t, z_rtt=z_rtt, pred_noise=pred_noise,
         failed_node=failed_node, imat_post=imat_post,
         accel_post=accel_post, mean_rtt_post=mean_rtt_post,
         preempted_node=preempted_node,
@@ -843,7 +883,7 @@ class SimStepper:
                     # carries the factor)
                     pred_basis = pred_basis * cold
                 eps = (1.0 - cfg.accuracy) * pred_basis
-                predicted = pred_basis + eps * prep.z_pred[:, j, :]
+                predicted = pred_basis + eps * cluster.z_pred_block[j]
             if graym is not None:
                 # AFTER the prediction basis is fixed: the multiply makes
                 # a fresh array, so a ``pred_basis is actual`` alias keeps
@@ -1029,7 +1069,7 @@ class SimStepper:
             if cold is not None and pred_basis is not actual:
                 pred_basis = pred_basis * cold
             eps = (1.0 - cfg.accuracy) * pred_basis
-            predicted = pred_basis + eps * prep.z_pred[:, j, :]
+            predicted = pred_basis + eps * cluster.z_pred_block[j]
         graym = self._gray_mult(now, candidates)
         if graym is not None:
             actual = actual * graym   # fresh array: the prediction

@@ -111,12 +111,18 @@ def test_threads_keep_their_own_open_spans():
 @pytest.mark.parametrize("scenario,policy", [("churn", "perf_aware"),
                                              ("baseline", "least_conn")])
 def test_compiled_call_records_its_five_steps(scenario, policy):
+    """... and the prediction noise's draw inside ``lower``, only where
+    the pass reads predictions (``least_conn`` reads none)."""
     run_scenario(scenario, policies=[policy], seeds=(0, 1),
                  include_oracle=False, backend="compiled", **SMALL)
     top = f"run:{policy}"
     steps = {f"{top}/{s}" for s in RUN_STEPS}
-    assert set(LAST_PHASES) == {"build", top} | steps
+    children = {f"{top}/lower/pred_noise"} if policy == "perf_aware" \
+        else set()
+    assert set(LAST_PHASES) == {"build", top} | steps | children
     assert sum(LAST_PHASES[s] for s in steps) <= LAST_PHASES[top]
+    for c in children:
+        assert LAST_PHASES[c] <= LAST_PHASES[f"{top}/lower"]
 
 
 def test_prepare_compiled_records_the_same_steps():
