@@ -72,7 +72,7 @@ def sweep(cell, devs, rates, seconds, seed):
     for r in rates:
         sched = gen.open_loop(dict(traffic, rate_per_s=r), seed, seconds,
                               mcfg.vocab_size)
-        reqs, t_wall, routed_at, route_s, busy, end, _ = serve.drive(
+        reqs, t_wall, routed_at, route_s, busy, end, _, _ = serve.drive(
             router, engines, sched, seconds, Tracer(False), seconds)
         done = np.array([np.inf if q.t_done is None else q.t_done - t_wall
                          for q in reqs])

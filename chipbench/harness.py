@@ -6,7 +6,9 @@ data or a file of its own:
 
 * ``BENCHMARK.json`` names the cell's configuration, traffic and chips;
 * ``configs/<config>.json`` holds the configuration as it is run, and its
-  ``kind`` names the module ``kinds/<kind>.py`` that runs it;
+  ``kind`` names the module ``kinds/<kind>.py`` that runs it; a served
+  configuration's ``arch`` names ``archs/<arch>.py``, all that depends
+  on the model's architecture;
 * ``traffic/<traffic>.json`` holds the traffic mix's parameters;
 * ``metrics/<metric>.py`` reads one metric from what the run recorded.
 """

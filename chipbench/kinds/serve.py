@@ -1,6 +1,11 @@
 """Cells of the served fleet: replicas of one model behind
 ``MorpheusRouter``, driven open loop.
 
+What depends on the model's architecture (its ``ModelConfig``, how each
+weight is drawn, its FLOPs, its smoke size, its plain reference) comes
+from ``chipbench/archs/<arch>.py``, which the configuration's ``arch``
+key names; this module holds none of it.
+
 Set-up makes the weights on the device from the seed in one jitted
 call, builds the fleet as ``launch/serve.build_fleet`` does with the
 cell's shapes (replicas sharing one parameter set, each with its own
@@ -24,82 +29,62 @@ and the served tokens, and the widest gap by which a served token's
 logit lies below the reference's best at that position (in units of
 the reference logits' spread there) is compared with its limit.  Tokens
 are greedy, so the gap is zero up to rounding.
+
+The record carries the program's counters without naming them: the
+engines' public numeric attributes and the span totals at the window's
+start, the trace's start and stop, and the loop's end (``drive``), so
+that a counter the program adds reaches a metric reader of its own.
 """
 from __future__ import annotations
 
 import gc
+import numbers
 import sys
 import time
-from typing import List
+from typing import Dict, List
 
 import numpy as np
 
-from chipbench import gen
+from chipbench import archs, gen
 from chipbench.harness import Cell, Check, Outcome, memory_peak_bytes
 
 #: seconds past the window's close that due requests are waited for
 GRACE_S = 60.0
 
 
+#: the architecture module that made each ModelConfig, whose ``draw``
+#: ``make_weights`` uses
+_MADE_BY: Dict[object, object] = {}
+
+
 def model_config(conf: dict, name: str = "served"):
-    """The program's ``ModelConfig`` for the configuration's keys."""
-    from repro.configs.base import MLAConfig, ModelConfig
-    return ModelConfig(
-        name=name, family="dense",
-        num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf["v_head_dim"], d_ff=conf["intermediate_size"],
-        vocab_size=conf["vocab_size"],
-        mla=MLAConfig(q_lora_rank=conf["q_lora_rank"],
-                      kv_lora_rank=conf["kv_lora_rank"],
-                      qk_nope_head_dim=conf["qk_nope_head_dim"],
-                      qk_rope_head_dim=conf["qk_rope_head_dim"],
-                      v_head_dim=conf["v_head_dim"]),
-        tie_embeddings=conf["tie_word_embeddings"],
-        rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        dtype=conf["torch_dtype"]).resolve(tp=1)
-
-
-def _fan_in(path: str, shape) -> int:
-    """Fan-in of a per-layer matrix (the layer axis dropped)."""
-    if path.endswith("attn/wo"):                  # (H, v, D)
-        return shape[0] * shape[1]
-    return shape[0]
+    """The program's ``ModelConfig`` for the configuration's keys, from
+    the architecture module that the configuration names."""
+    arch = archs.of(conf)
+    mcfg = arch.model_config(conf, name)
+    if _MADE_BY.setdefault(mcfg, arch) is not arch:
+        raise ValueError(f"{_MADE_BY[mcfg].__name__} and {arch.__name__} "
+                         f"make the same ModelConfig {name!r}")
+    return mcfg
 
 
 def make_weights(mcfg, seed: int):
     """Seeded random weights in the program's layout and dtypes, made on
-    the device by one jitted call: matrices N(0, 1/fan_in), the
-    embedding N(0, 0.02^2), norm scales 1 + N(0, 0.1^2)."""
+    the device by one jitted call; each leaf drawn by the ``draw`` of the
+    architecture module that made ``mcfg`` (``model_config``)."""
     import jax
-    import jax.numpy as jnp
     from repro.models import model as M
+    draw = _MADE_BY[mcfg].draw
     shapes = jax.eval_shape(lambda k: M.init_params(k, mcfg),
                             jax.random.PRNGKey(0))
     flat, tree = jax.tree_util.tree_flatten_with_path(shapes)
     paths = ["/".join(str(getattr(k, "key", k)) for k in p) for p, _ in flat]
 
-    def one(key, path, sd):
-        leaf = path.rsplit("/", 1)[-1]
-        if leaf in ("scale", "q_norm", "kv_norm"):
-            x = 1.0 + 0.1 * jax.random.normal(key, sd.shape, jnp.float32)
-            return x.astype(sd.dtype)
-        if leaf == "tok":
-            std = 0.02
-        else:
-            per_layer = sd.shape[1:] if path.startswith("layers") \
-                else sd.shape
-            std = _fan_in(path, per_layer) ** -0.5
-        return (jax.random.normal(key, sd.shape, sd.dtype)
-                * jnp.asarray(std, sd.dtype))
-
     @jax.jit
     def build(lo, hi):
         key = jax.random.fold_in(jax.random.PRNGKey(lo), hi)
         return jax.tree_util.tree_unflatten(tree, [
-            one(jax.random.fold_in(key, i), p, sd)
+            draw(jax.random.fold_in(key, i), p, sd)
             for i, (p, (_, sd)) in enumerate(zip(paths, flat))])
 
     s = int(seed)
@@ -139,30 +124,60 @@ def build_fleet(mcfg, params, conf: dict, sched: gen.Schedule,
     return router, engines
 
 
+def counters(engines) -> Dict[str, float]:
+    """Each engine's public numeric attributes, summed over the
+    replicas: whatever counter the program's ``ServingEngine`` keeps."""
+    out: Dict[str, float] = {}
+    for e in engines:
+        for k, v in vars(e).items():
+            if not k.startswith("_") and isinstance(v, numbers.Real):
+                out[k] = out.get(k, 0.0) + float(v)
+    return out
+
+
 def drive(router, engines, sched: gen.Schedule, seconds: float, tracer,
           trace_seconds: float):
     """The open-loop window; a traced run traces its last
     ``trace_seconds``.  Returns the requests, the window's start on the
     wall clock, each request's route time (seconds after the start), the
-    route call durations, the engines' busy seconds, the loop's end, and
+    route call durations, the engines' busy seconds, the loop's end,
     ``span``: the part of the window that the per-layer metrics read,
     from its start to the trace's stop (stopping a trace blocks the
-    loop, and what follows would count the stall)."""
+    loop, and what follows would count the stall), and ``marks``: at the
+    window's start (``start``), the trace's start and stop
+    (``trace_start``, ``trace_stop``, in a traced run) and the loop's end
+    (``end``), the time after the start (``t``), the engines'
+    ``counters`` (``engines``) and the program's span totals
+    (``spans``, ``repro.core.telemetry.snapshot()``)."""
     import jax
+    from repro.core.telemetry import snapshot
     from repro.serving.engine import Request
     reqs = [Request(rid=i, tokens=sched.tokens[i],
                     max_new_tokens=int(sched.new_tokens[i]))
             for i in range(len(sched))]
     routed_at = np.full(len(reqs), np.nan)
     route_s: List[float] = []
-    busy0 = sum(e.busy_s for e in engines)
+    marks = {}
+
+    def mark(name):
+        marks[name] = {"t": time.time() - t0, "engines": counters(engines),
+                       "spans": snapshot()}
+
+    def busy(name):
+        return (marks[name]["engines"]["busy_s"]
+                - marks["start"]["engines"]["busy_s"])
+
     span = None
     t0 = time.time()
+    mark("start")
     i = 0
     while True:
         now = time.time() - t0
-        if span is None and now >= seconds - trace_seconds:
+        if span is None and now >= seconds - trace_seconds \
+                and not tracer.active:
             tracer.start()
+            if tracer.active:
+                mark("trace_start")
         while i < len(reqs) and sched.due[i] <= now:
             a = time.perf_counter()
             with jax.profiler.TraceAnnotation("route"):
@@ -181,8 +196,9 @@ def drive(router, engines, sched: gen.Schedule, seconds: float, tracer,
             break
         now = time.time() - t0
         if tracer.active and now >= seconds:
+            mark("trace_stop")
             span = {"t": now, "routes": len(route_s),
-                    "busy": sum(e.busy_s for e in engines) - busy0}
+                    "busy": busy("trace_stop")}
             tracer.stop()
             print(f"trace: stopping it took {time.time() - t0 - now:.3f} s",
                   file=sys.stderr, flush=True)
@@ -190,10 +206,11 @@ def drive(router, engines, sched: gen.Schedule, seconds: float, tracer,
             break
     end = time.time() - t0
     tracer.stop()
-    busy = sum(e.busy_s for e in engines) - busy0
+    mark("end")
     if span is None:
-        span = {"t": min(end, seconds), "routes": len(route_s), "busy": busy}
-    return reqs, t0, routed_at, route_s, busy, end, span
+        span = {"t": min(end, seconds), "routes": len(route_s),
+                "busy": busy("end")}
+    return reqs, t0, routed_at, route_s, busy("end"), end, span, marks
 
 
 def sample(reqs, ok: np.ndarray, seed: int, tokens: int, most: int):
@@ -244,11 +261,11 @@ def logit_gap(params, conf: dict, reqs, picked, fp8: bool = False,
     deviation there, so that one limit holds at any width (``fp8``: the
     gap of the tokens the float8 control puts first)."""
     import jax.numpy as jnp
-    from chipbench.refs import minicpm3
+    logits_at = archs.of(conf).logits_at
     toks, rows, cols, served = gap_inputs(reqs, picked, shape)
-    ref = minicpm3.logits_at(params, conf, toks, rows, cols)
+    ref = logits_at(params, conf, toks, rows, cols)
     if fp8:
-        low = minicpm3.logits_at(params, conf, toks, rows, cols, fp8=True)
+        low = logits_at(params, conf, toks, rows, cols, fp8=True)
         served = jnp.argmax(low, -1)
     got = jnp.take_along_axis(ref, jnp.asarray(served)[:, None], -1)[:, 0]
     gap = (ref.max(-1) - got) / ref.std(-1)
@@ -268,7 +285,7 @@ def run(cell: Cell, *, seed: int, seconds: float, tracer, t0: float,
                                   int(traffic["new_tokens"]["min"]), seed)
     setup_s = time.perf_counter() - t0
 
-    reqs, t_wall, routed_at, route_s, busy, end, span = drive(
+    reqs, t_wall, routed_at, route_s, busy, end, span, marks = drive(
         router, engines, sched, seconds, tracer,
         traffic.get("trace_seconds", seconds))
     peak = memory_peak_bytes(devs)
@@ -278,6 +295,8 @@ def run(cell: Cell, *, seed: int, seconds: float, tracer, t0: float,
     n = len(reqs)
     done_at = np.array([np.nan if r.t_done is None else r.t_done - t_wall
                         for r in reqs])
+    started_at = np.array([np.nan if r.t_start is None
+                           else r.t_start - t_wall for r in reqs])
     ok = np.array([r.output is not None and r.output.shape
                    == (r.max_new_tokens,) for r in reqs]) & ~np.isnan(done_at)
     rtt = np.where(ok, done_at - sched.due, np.inf)
@@ -308,6 +327,7 @@ def run(cell: Cell, *, seed: int, seconds: float, tracer, t0: float,
         "prompt_len": sched.prompt_len, "new_tokens": sched.new_tokens,
         "route_s": np.asarray(route_s),
         "span": span, "config": conf, "device_kind": devs[0].device_kind,
+        "t_start_s": started_at, "marks": marks,
     }
     return Outcome(setup_s=setup_s, attempted=n, failed=failed,
                    checks=checks, memory_peak_bytes=peak, record=record)

@@ -9,7 +9,7 @@ from typing import Optional
 
 import numpy as np
 
-from chipbench import flops
+from chipbench import archs, flops
 
 
 def percentile(values, q: float) -> Optional[float]:
@@ -86,15 +86,17 @@ def engine_busy_share(record) -> Optional[float]:
 
 def model_mfu(record) -> Optional[float]:
     """Model FLOPs of the requests completed in the span (their prefill
-    and their own decode steps) over the span at the chip's peak bf16
-    rate, in percent."""
+    and their own decode steps, as the configuration's architecture
+    module counts them) over the span at the chip's peak bf16 rate, in
+    percent."""
     span = record.get("span")
     if not span or span["t"] <= 0:
         return None
     conf = record["config"]
+    request_flops = archs.of(conf).request_flops
     done = np.flatnonzero(record["done_at_s"] <= span["t"])
-    total = sum(flops.request_flops(conf, int(record["prompt_len"][i]),
-                                    int(record["new_tokens"][i]))
+    total = sum(request_flops(conf, int(record["prompt_len"][i]),
+                              int(record["new_tokens"][i]))
                 for i in done)
     peak = flops.peaks(record["device_kind"])["bf16_flops"]
     return 100.0 * total / (span["t"] * peak)

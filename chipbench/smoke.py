@@ -3,7 +3,7 @@ harness's look for a chip skipped: the rehearsal the tests make."""
 import time
 import types
 
-from chipbench import harness
+from chipbench import archs, harness
 
 #: a device that names the benchmark's chip, for the readers that look
 #: up its peaks; nothing here runs on it
@@ -12,29 +12,28 @@ DEVICE = types.SimpleNamespace(platform="cpu", device_kind="TPU v5 lite",
 
 SIM_SMALL = dict(n_nodes=30, replicas_total=30, n_requests=120,
                  trials_per_seed=4, seeds_per_call=2)
-SERVE_SMALL = dict(num_hidden_layers=2, hidden_size=64,
-                   num_attention_heads=4, num_key_value_heads=4,
-                   intermediate_size=128, vocab_size=512, q_lora_rank=32,
-                   kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
-                   v_head_dim=8)
 
 
-def small(name: str):
-    """The cell ``name`` of BENCHMARK.json cut to a size the CPU runs in
-    seconds: widths and depth of the smoke model, a few replicas and
-    requests at a rate that gives each run several, millisecond
-    slowdowns."""
-    c = harness.load_cell(name)
+def cut(c: harness.Cell) -> harness.Cell:
+    """The cell ``c`` cut to a size the CPU runs in seconds: widths and
+    depth of the smoke model (its architecture module's ``SMOKE``), a few
+    replicas and requests at a rate that gives each run several,
+    millisecond slowdowns."""
     if c.config["kind"] == "sim":
         c.config.update(SIM_SMALL)
     else:
-        c.config.update(SERVE_SMALL)
+        c.config.update(archs.of(c.config).SMOKE)
         c.config["fleet"]["slowdowns_s"] = [0.0, 0.002, 0.004]
         c.traffic.update(rate_per_s=4.0,
                          prompt=dict(median=16, sigma=0.0, min=16, max=16),
                          new_tokens=dict(median=4, sigma=0.6, min=2, max=8),
                          trace_seconds=1)
     return c
+
+
+def small(name: str) -> harness.Cell:
+    """The cell ``name`` of BENCHMARK.json, ``cut``."""
+    return cut(harness.load_cell(name))
 
 
 def run_small(name: str, seed: int = 2 ** 33 + 5, seconds: float = 2.0,

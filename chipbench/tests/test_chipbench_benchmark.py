@@ -7,7 +7,7 @@ import re
 import numpy as np
 import pytest
 
-from chipbench import harness
+from chipbench import archs, harness
 
 ROOT = harness.ROOT
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -40,8 +40,14 @@ def test_every_name_and_unit_is_well_formed(bench):
 def test_files_exist_for_every_entry(bench, section):
     for x in bench[section]:
         if section == "configs":
-            path = os.path.join(ROOT, x["file"])
-            assert json.load(open(path))["kind"] in ("sim", "serve")
+            conf = harness.load_json(os.path.join(ROOT, x["file"]))
+            # the harness runs kinds/<kind>.py; a served configuration
+            # names its architecture module, which gives the interface
+            assert os.path.exists(os.path.join(
+                harness.HERE, "kinds", conf["kind"] + ".py"))
+            if conf["kind"] == "serve":
+                arch = archs.of(conf)
+                assert all(hasattr(arch, k) for k in archs.INTERFACE)
         else:
             assert os.path.exists(os.path.join(
                 harness.HERE, "traffic", x["traffic"] + ".json"))
